@@ -81,11 +81,12 @@ def swap_microbench(params, qlayers, cfg, slots, backend, reps=50):
     state = lstm_lm.init_quant_decode_state(qlayers, slots,
                                             per_slot_len=True)
     step, _, _, _, _, write = E._engine_step_fns(qlayers, cfg, backend)
+    weights = E.serving_weights(params, qlayers)
     pool = StatePool()
     toks = jnp.zeros((slots,), jnp.int32)
     active = jnp.ones((slots,), bool)
     # warm every program (compile outside the timed region)
-    _, state = step(params, toks, state, active)
+    _, state = step(weights, toks, state, active)
     pool.put(-1, jax.device_get(lstm_lm.slice_state(state, 0)))
     state = write(state, jnp.int32(0), pool.take(-1))
     jax.block_until_ready(state["h"][0])
@@ -101,7 +102,7 @@ def swap_microbench(params, qlayers, cfg, slots, backend, reps=50):
     resume_us = (time.perf_counter() - t0) / reps * 1e6
     t0 = time.perf_counter()
     for _ in range(reps):
-        _, state = step(params, toks, state, active)
+        _, state = step(weights, toks, state, active)
     jax.block_until_ready(state["h"][0])
     step_us = (time.perf_counter() - t0) / reps * 1e6
     return {

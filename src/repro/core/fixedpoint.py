@@ -429,8 +429,12 @@ def integer_rsqrt_multiplier(hi, lo, extra_pow2: int = 0) -> Tuple[jax.Array, ja
     lzc = jnp.clip(lz, 0, 63)
     lz_lt32 = lzc < 32
     sh = jnp.where(lz_lt32, lzc, lzc - 32).astype(jnp.uint32)
+    # where(), not maximum(): an unsigned max (arith.maxui) has no Mosaic
+    # lowering, and this runs inside the persistent TPU kernel
+    sh_pos = sh > 0
     lo_part = jnp.where(
-        sh > 0, lo >> (jnp.uint32(32) - jnp.maximum(sh, 1)), jnp.uint32(0)
+        sh_pos, lo >> (jnp.uint32(32) - jnp.where(sh_pos, sh, 1)),
+        jnp.uint32(0)
     )
     top_lt = (hi << sh) | lo_part
     top_ge = lo << sh

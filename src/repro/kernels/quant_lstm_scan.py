@@ -15,9 +15,11 @@ buffer per leaf, seeded at ``t == 0``.  Each grid step fuses
 which eliminates the per-timestep dispatch overhead and the per-step state
 HBM round-trips the scan-of-steps executor pays: between consecutive
 timesteps nothing leaves VMEM.  The input-dependent work arrives
-precomputed -- the kernel consumes per-step ``(B, 1, G*H)`` int32 blocks of
+precomputed -- the kernel consumes per-step ``(B, G*H)`` int32 blocks of
 the hoisted time-batched input GEMM (``ops.quant_recurrent_input_proj``),
-so the only matmul on the critical scan path is the genuinely sequential
+laid out time-major so each block spans the array's last two dims (the
+TPU lowering tiles those by (8, 128) unless they are full extents), so
+the only matmul on the critical scan path is the genuinely sequential
 ``h_{t-1} @ R_cat`` product.
 
 The step math is ``ref.recurrent_step_jnp`` -- the same cell dispatch the
@@ -35,8 +37,9 @@ leaf for rows past their valid prefix -- the chunked-prefill contract of
 
 Sizing note: blocks span the full ``(B, ...)`` extents (integer LayerNorm
 reduces over the whole hidden axis, and the carry must stay resident), so
-``B * (G*H)`` int32 plus the packed weights must fit in VMEM; serving-shape
-blocks (B <= 64, H <= 2048) do.  Time is the grid, so T is unbounded.
+``B * (G*H)`` int32 plus the packed weights must fit in VMEM: at H = 2048
+(``lstm-rnnt``, ``gru-rnnt``) and B <= 8 they fit the v5e's 16 MB scoped
+limit (``tests/test_tpu_compile.py``).  Time is the grid, so T is unbounded.
 """
 from __future__ import annotations
 
@@ -55,6 +58,12 @@ from . import ref
 # Consumed by the hoisted input GEMM, never by the recurrent stage.
 _INPUT_GEMM_KEYS = ("W_cat", "fold_x_cat")
 
+# Rows of a 32-bit TPU vector register: the kernel's batch is padded to a
+# multiple of this (see ``quant_recurrent_seq_scan_pallas``), and the
+# integer LM's head runs its rows in blocks of this size
+# (``lstm_lm._quant_head``).
+SUBLANES = 8
+
 
 def _recurrent_vals(arrays: Dict[str, Any]):
     """Deterministic flat view of the recurrent-stage arrays.
@@ -70,7 +79,7 @@ def _recurrent_vals(arrays: Dict[str, Any]):
 def _scan_kernel(*refs, spec, treedef, n_vals: int, n_state: int,
                  masked: bool):
     it = iter(refs)
-    acc_ref = next(it)  # (B, 1, G*H) int32: step slice of the hoisted GEMM
+    acc_ref = next(it)  # (B, G*H) int32: step slice of the hoisted GEMM
     val_refs = [next(it) for _ in range(n_vals)]  # VMEM-resident all sweep
     s0_refs = [next(it) for _ in range(n_state)]  # t=0 carry seeds
     vl_ref = next(it) if masked else None
@@ -88,13 +97,13 @@ def _scan_kernel(*refs, spec, treedef, n_vals: int, n_state: int,
     state = tuple(scr[...] for scr in scrs)
     vals = jax.tree_util.tree_unflatten(treedef, [r[...] for r in val_refs])
     new_state = ref.recurrent_step_jnp(
-        vals, spec, acc_ref[...][:, 0, :], state)
+        vals, spec, acc_ref[...], state)
     if masked:
-        live = (vl_ref[...] > t)[:, None]
+        live = vl_ref[...] > t  # (B, 1): broadcasts over every leaf's width
         new_state = tuple(
             jnp.where(live, new, old)
             for new, old in zip(new_state, state))
-    ys_ref[...] = new_state[0][:, None, :]  # leaf 0 is the emitted output
+    ys_ref[...] = new_state[0]  # leaf 0 is the emitted output
     for scr, new in zip(scrs, new_state):
         scr[...] = new
 
@@ -118,7 +127,26 @@ def quant_recurrent_seq_scan_pallas(
 
     Returns ``(ys int8 (B, T, d_out), state_final)`` -- bit-identical to
     scanning ``ops.quant_recurrent_step`` over the same slices.
+
+    The batch runs padded to whole sublane tiles (B=1, the single-stream
+    decode shape, runs as 8 rows).  Below 8 rows the TPU compiler lays the
+    ``(B, G*H)`` intermediates out with partial-tile layouts, and at
+    ``lstm-rnnt`` width a B=1 kernel then asks for 20.5 MB of scoped VMEM
+    (limit 16 MB) after minutes of compiling; padded, it compiles like B=8.
+    Rows never interact (per-row matmuls, LayerNorm over the hidden axis
+    only), so the padding rows are dropped and the real rows are unchanged.
     """
+    B = acc_x_all.shape[0]
+    B_pad = -(-B // SUBLANES) * SUBLANES
+    if B_pad != B:
+        def pad(x):
+            return jnp.pad(x, [(0, B_pad - B)] + [(0, 0)] * (x.ndim - 1))
+
+        ys, state = quant_recurrent_seq_scan_pallas(
+            arrays, spec, pad(acc_x_all), tuple(pad(s) for s in state0),
+            None if valid_len is None else pad(valid_len),
+            interpret=interpret)
+        return ys[:B], tuple(s[:B] for s in state)
     B, T, GH = acc_x_all.shape
     cell = C.get_cell(spec)
     leaves = cell.state_leaves(spec)
@@ -131,13 +159,21 @@ def quant_recurrent_seq_scan_pallas(
         """Whole-array block revisited every grid step (stays in VMEM)."""
         return pl.BlockSpec(shape, lambda t, _n=len(shape): (0,) * _n)
 
-    inputs = [acc_x_all, *vals_flat, *state0]
-    in_specs = [pl.BlockSpec((B, 1, GH), lambda t: (0, t, 0))]
+    def step_block(width):
+        """Time-major ``(T, B, width)`` array, one ``(B, width)`` step per
+        grid index: its last two block dims are the array's full extents,
+        which Mosaic accepts at any B and width."""
+        return pl.BlockSpec((pl.squeezed, B, width), lambda t: (t, 0, 0))
+
+    inputs = [jnp.swapaxes(acc_x_all, 0, 1), *vals_flat, *state0]
+    in_specs = [step_block(GH)]
     in_specs += [const(v.shape) for v in vals_flat]
     in_specs += [const((B, leaf.width)) for leaf in leaves]
     if masked:
-        inputs.append(valid_len)
-        in_specs.append(const((B,)))
+        # (B, 1), not (B,): a 1-D mask would need a vector reshape to
+        # broadcast over the leaves, which Mosaic cannot lower
+        inputs.append(valid_len.reshape(B, 1))
+        in_specs.append(const((B, 1)))
 
     outs = pl.pallas_call(
         functools.partial(
@@ -146,11 +182,11 @@ def quant_recurrent_seq_scan_pallas(
         grid=(T,),
         in_specs=in_specs,
         out_specs=(
-            [pl.BlockSpec((B, 1, d_out), lambda t: (0, t, 0))]
+            [step_block(d_out)]
             + [const((B, leaf.width)) for leaf in leaves]
         ),
         out_shape=(
-            [jax.ShapeDtypeStruct((B, T, d_out), jnp.int8)]
+            [jax.ShapeDtypeStruct((T, B, d_out), jnp.int8)]
             + [jax.ShapeDtypeStruct((B, leaf.width), leaf.dtype)
                for leaf in leaves]
         ),
@@ -159,7 +195,7 @@ def quant_recurrent_seq_scan_pallas(
         ],
         interpret=interpret,
     )(*inputs)
-    return outs[0], tuple(outs[1:])
+    return jnp.swapaxes(outs[0], 0, 1), tuple(outs[1:])
 
 
 def quant_lstm_seq_scan_pallas(

@@ -53,6 +53,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.launch.scheduler import (Decision, Scheduler, StreamView,
                                     get_scheduler)
@@ -277,8 +278,8 @@ class MigratedStream:
     pending: bool = False
 
 
-_ENGINE_FNS: Dict[Tuple[int, str], Tuple[Any, ...]] = {}
-_FN_CACHE_MAX = 8  # each entry pins a model's arrays + compiled programs
+_ENGINE_FNS: Dict[Tuple[Any, ...], Tuple[Any, ...]] = {}
+_FN_CACHE_MAX = 8  # each entry pins a set of compiled programs
 
 
 def _cache_put(cache: Dict, key, value) -> None:
@@ -289,16 +290,38 @@ def _cache_put(cache: Dict, key, value) -> None:
     cache[key] = value
 
 
+def serving_weights(params, qlayers):
+    """Everything the integer serving programs read, as ONE jit argument:
+    the float embedding / LM-head params (the float recurrent stack stays
+    behind) and each layer's quantized arrays.
+
+    The programs take these as arguments, never as closure constants: a
+    closed-over array is baked into the executable, so every program (and
+    every compile-cache entry) would carry its own copy of the weights, and
+    the weights could not follow an engine onto its own devices.
+    """
+    head = {k: v for k, v in params.items() if k != "lstm"}
+    return head, [arrays for arrays, _ in qlayers]
+
+
+def _unpack(weights, specs):
+    """``serving_weights`` (traced) + static specs -> ``(params, qlayers)``."""
+    params, qarrays = weights
+    return params, list(zip(qarrays, specs))
+
+
 def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
     """Jitted (step, chunk_step, chunk_advance, verify, reset, write)
-    programs for the engine loop.
+    programs for the engine loop.  The model programs take
+    ``serving_weights(params, qlayers)`` as their first argument.
 
-    Cached per (qlayers identity, backend) when no sharding constrain is
+    Cached per (layer specs, cfg, backend) when no sharding constrain is
     installed, so property tests and repeated engine instances over the
-    same quantized model share compiled programs (the jit itself also
+    same model shapes share compiled programs (the jit itself also
     specializes per slot count / chunk size via input shapes).
     """
-    key = (id(qlayers), backend)
+    specs = tuple(spec for _, spec in qlayers)
+    key = (specs, cfg, backend)
     if constrain is None and key in _ENGINE_FNS:
         return _ENGINE_FNS[key]
 
@@ -313,7 +336,7 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
                           for leaf in out[k]]
         return out
 
-    def step(params, tokens, state, active):
+    def step(weights, tokens, state, active):
         """One engine iteration: all slots advance one token.
 
         tokens: (S,) int32; active: (S,) bool.  Returns the per-slot
@@ -321,8 +344,9 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
         row-wise computation is identical to a batch-1 decode, so the
         argmax is too) and the new state with inactive rows frozen.
         """
+        params, layers = _unpack(weights, specs)
         logits, new_state = lstm_lm.quant_forward(
-            params, qlayers, cfg, tokens[:, None], state, backend=backend)
+            params, layers, cfg, tokens[:, None], state, backend=backend)
         greedy = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
         mask = active[:, None]
         out = {
@@ -333,7 +357,7 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
         out["len"] = state["len"] + active.astype(jnp.int32)
         return greedy, constrain_state(out)
 
-    def chunk_step(params, tokens, state, valid):
+    def chunk_step(weights, tokens, state, valid):
         """One chunked-prefill iteration: slot i advances valid[i] tokens.
 
         tokens: (S, K) int32; valid: (S,) int32 in [0, K].  The ragged
@@ -345,12 +369,13 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
         each row's LAST VALID position -- the only logits computed from
         live state.
         """
+        params, layers = _unpack(weights, specs)
         logits, out = lstm_lm.quant_chunk_step(
-            params, qlayers, cfg, tokens, state, valid, backend=backend)
+            params, layers, cfg, tokens, state, valid, backend=backend)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return greedy, constrain_state(out)
 
-    def verify(params, tokens, state, valid, draft_len):
+    def verify(weights, tokens, state, valid, draft_len):
         """One speculative verify iteration over a ``(S, W)`` block.
 
         Row i's first ``valid[i] - draft_len[i]`` positions are committed
@@ -364,18 +389,20 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
         pre-step state, the same executor chunked prefill trusts).  Idle
         rows (``valid == 0``) stay frozen, subsuming the active mask.
         """
+        params, layers = _unpack(weights, specs)
         pred, accepted, out = lstm_lm.quant_verify_step(
-            params, qlayers, cfg, tokens, state, valid, draft_len,
+            params, layers, cfg, tokens, state, valid, draft_len,
             backend=backend)
         return pred, accepted, constrain_state(out)
 
-    def chunk_advance(params, tokens, state, valid):
+    def chunk_advance(weights, tokens, state, valid):
         """Chunked iteration where NO slot emits a token this step (every
         active row is mid-prompt with > K tokens still to feed): advance
         state only, no LM head, no greedy output -- so the engine loop can
         dispatch consecutive prefill chunks without a host sync."""
+        params, layers = _unpack(weights, specs)
         out = lstm_lm.quant_chunk_advance(
-            params, qlayers, cfg, tokens, state, valid, backend=backend)
+            params, layers, cfg, tokens, state, valid, backend=backend)
         return constrain_state(out)
 
     def write(state, slot, row_state):
@@ -388,8 +415,9 @@ def _engine_step_fns(qlayers, cfg, backend: str, constrain=None):
         jax.jit(chunk_step),
         jax.jit(chunk_advance),
         jax.jit(verify),
+        # the reset reads only the specs (each leaf's reset value)
         jax.jit(lambda state, slot: lstm_lm.reset_quant_slot(
-            qlayers, state, slot)),
+            [(None, spec) for spec in specs], state, slot)),
         jax.jit(write),
     )
     if constrain is None:
@@ -501,6 +529,7 @@ class ContinuousBatchingEngine:
         self.schedule_log: List[Tuple[int, str, int, int]] = []
         self._state = lstm_lm.init_quant_decode_state(
             qlayers, n_slots, per_slot_len=True)
+        self.weights = serving_weights(params, qlayers)
         constrain = None
         self._put = lambda x: x
         self._put_row = lambda tree: tree
@@ -510,7 +539,15 @@ class ContinuousBatchingEngine:
             self._state = jax.device_put(
                 self._state,
                 shlib.engine_state_shardings(self._state, rules, mesh))
-            constrain = shlib.make_constrain(rules, mesh)
+            # a replica of the weights on this engine's own devices: no
+            # engine of a fleet reads its weights from another's chip
+            self.weights = jax.device_put(
+                self.weights, NamedSharding(mesh, PartitionSpec()))
+            # a one-device mesh has nothing to constrain, so its engines
+            # (every one-chip fleet shard, and each restart) share the
+            # cached step programs
+            if mesh.size > 1:
+                constrain = shlib.make_constrain(rules, mesh)
             # only two input shapes ever occur ((S,) and (S, K)): resolve
             # each sharding once, not twice per step on the serving hot loop
             shard_cache: Dict[Tuple[int, ...], Any] = {}
@@ -1014,7 +1051,7 @@ class ContinuousBatchingEngine:
             # one-token / chunked paths, which emit at most one token)
             if drafts:
                 pred, accepted, self._state = self._verify(
-                    self.params, self._put(jnp.asarray(tokens)),
+                    self.weights, self._put(jnp.asarray(tokens)),
                     self._state, self._put(jnp.asarray(valid)),
                     self._put(jnp.asarray(draft_len)))
                 preds = np.asarray(pred)
@@ -1022,7 +1059,7 @@ class ContinuousBatchingEngine:
                 spec_steps += 1
             elif width == 1:
                 greedy, self._state = self._step_fn(
-                    self.params, self._put(jnp.asarray(tokens[:, 0])),
+                    self.weights, self._put(jnp.asarray(tokens[:, 0])),
                     self._state, self._put(jnp.asarray(valid > 0)))
                 preds = np.asarray(greedy)[:, None]
                 consumed = valid
@@ -1039,7 +1076,7 @@ class ContinuousBatchingEngine:
                 consumed = valid
                 if emits:
                     greedy, self._state = self._chunk_step(
-                        self.params, self._put(jnp.asarray(tokens)),
+                        self.weights, self._put(jnp.asarray(tokens)),
                         self._state, self._put(jnp.asarray(valid)))
                     # the chunked head reads each row's LAST VALID position,
                     # the only one the emission rule below can select
@@ -1051,7 +1088,7 @@ class ContinuousBatchingEngine:
                 else:
                     preds = None  # never read: no row emits this step
                     self._state = self._chunk_advance(
-                        self.params, self._put(jnp.asarray(tokens)),
+                        self.weights, self._put(jnp.asarray(tokens)),
                         self._state, self._put(jnp.asarray(valid)))
             now = time.perf_counter()
             for i, s in enumerate(slot_streams):
@@ -1155,26 +1192,33 @@ class ContinuousBatchingEngine:
 # ---------------------------------------------------------------------------
 
 
-_SINGLE_FNS: Dict[Tuple[int, str], Tuple[Any, Any]] = {}
+_SINGLE_FNS: Dict[Tuple[Any, ...], Tuple[Any, Any]] = {}
 
 
 def single_stream_fns(qlayers, cfg, backend: str = "xla"):
-    """Jitted (prefill, decode) pair for batch-1 serving, cached per
-    (qlayers identity, backend) so repeated ``decode_single`` calls reuse
-    the compiled programs instead of re-tracing fresh closures."""
-    key = (id(qlayers), backend)
+    """Jitted (prefill, decode) pair for batch-1 serving, taking
+    ``serving_weights`` as first argument and cached per (layer specs, cfg,
+    backend), so repeated ``decode_single`` calls reuse the compiled
+    programs instead of re-tracing fresh closures."""
+    specs = tuple(spec for _, spec in qlayers)
+    key = (specs, cfg, backend)
     if key not in _SINGLE_FNS:
-        prefill_fn = jax.jit(lambda p, t, s: lstm_lm.quant_prefill(
-            p, qlayers, cfg, t, s, backend=backend))
-        decode_fn = jax.jit(lambda p, t, s: lstm_lm.quant_decode_step(
-            p, qlayers, cfg, t, s, backend=backend))
-        _cache_put(_SINGLE_FNS, key, (prefill_fn, decode_fn))
+        def prefill(weights, tokens, state):
+            params, layers = _unpack(weights, specs)
+            return lstm_lm.quant_prefill(
+                params, layers, cfg, tokens, state, backend=backend)
+
+        def decode(weights, token, state):
+            params, layers = _unpack(weights, specs)
+            return lstm_lm.quant_decode_step(
+                params, layers, cfg, token, state, backend=backend)
+
+        _cache_put(_SINGLE_FNS, key, (jax.jit(prefill), jax.jit(decode)))
     return _SINGLE_FNS[key]
 
 
 def decode_single(params, qlayers, cfg, prompt, max_new_tokens: int, *,
-                  backend: str = "xla",
-                  prefill_fn=None, decode_fn=None) -> List[int]:
+                  backend: str = "xla") -> List[int]:
     """Decode ONE stream alone: scanned prefill + greedy loop.
 
     The bit-exactness oracle for the engine (and the naive serving baseline
@@ -1183,16 +1227,14 @@ def decode_single(params, qlayers, cfg, prompt, max_new_tokens: int, *,
     distinct prompt length).
     """
     prompt = jnp.asarray(np.asarray(prompt, np.int32).reshape(1, -1))
-    if prefill_fn is None or decode_fn is None:
-        pf, df = single_stream_fns(qlayers, cfg, backend)
-        prefill_fn = prefill_fn or pf
-        decode_fn = decode_fn or df
+    prefill_fn, decode_fn = single_stream_fns(qlayers, cfg, backend)
+    weights = serving_weights(params, qlayers)
     state = lstm_lm.init_quant_decode_state(qlayers, 1)
-    logits, state = prefill_fn(params, prompt, state)
+    logits, state = prefill_fn(weights, prompt, state)
     out = [int(jnp.argmax(logits, -1)[0])]
     for _ in range(max_new_tokens - 1):
         tok = jnp.asarray([[out[-1]]], jnp.int32)
-        logits, state = decode_fn(params, tok, state)
+        logits, state = decode_fn(weights, tok, state)
         out.append(int(jnp.argmax(logits, -1)[0]))
     return out
 

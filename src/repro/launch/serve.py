@@ -59,10 +59,31 @@ and shares the default device otherwise.
 from __future__ import annotations
 
 import argparse
+import os
 import time
 
 import jax
 import jax.numpy as jnp
+
+# <repo>/.jax_cache: a fixed path, because the cache key includes it
+_DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, ".jax_cache"))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads
+    the variable itself, and nothing else is set here).  Otherwise the
+    cache lives in the repository's ``.jax_cache``.  Call it before the
+    first compile.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    return _DEFAULT_CACHE_DIR
 
 
 def _scan_prefill(decode, params, prompt, state):
@@ -289,6 +310,7 @@ def _serve_int8_recurrent(args, cfg) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -307,6 +307,32 @@ def stack_state(state_list):
     return out
 
 
+def _quant_head(params, x):
+    """LM head over float rows ``x (..., d)`` -> bf16 logits ``(..., V)``,
+    evaluated in zero-padded blocks of exactly ``SUBLANES`` rows.
+
+    A float matmul's rounding depends on its row count (the compiler tiles
+    it by shape), so the same hidden row could give different logits -- and
+    a different greedy token -- in the engine's S-row batch than in
+    decode_single's one row.  Within one block shape, a row's logits depend
+    on that row alone.
+    """
+    from repro.kernels.quant_lstm_scan import SUBLANES
+
+    lead, d = x.shape[:-1], x.shape[-1]
+    rows = x.reshape(-1, d).astype(jnp.bfloat16)
+    n = rows.shape[0]
+    n_pad = -(-n // SUBLANES) * SUBLANES
+    blocks = jnp.pad(rows, ((0, n_pad - n), (0, 0))).reshape(
+        -1, SUBLANES, d)
+    # the barriers keep the compiler from slicing the padding back off
+    # (a 1-row program would otherwise run a 1-row matmul again)
+    bar = jax.lax.optimization_barrier
+    logits = jax.lax.map(
+        lambda b: bar(emb.logits_head(params, bar(b))), blocks)
+    return logits.reshape(n_pad, -1)[:n].reshape(*lead, -1)
+
+
 def _quant_stack(params, qlayers, tokens, states, backend, valid_len=None):
     """Run the integer recurrent stack over a ``(B, T)`` token block.
 
@@ -351,8 +377,7 @@ def quant_forward(params, qlayers, cfg: ArchConfig, tokens, states,
     """
     x, new_states = _quant_stack(params, qlayers, tokens, states, backend,
                                  valid_len)
-    logits = emb.logits_head(params, x.astype(jnp.bfloat16))
-    return logits, new_states
+    return _quant_head(params, x), new_states
 
 
 def quant_chunk_step(params, qlayers, cfg: ArchConfig, tokens, states,
@@ -371,8 +396,7 @@ def quant_chunk_step(params, qlayers, cfg: ArchConfig, tokens, states,
                                  valid_len)
     idx = jnp.maximum(valid_len - 1, 0)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    logits = emb.logits_head(params, last.astype(jnp.bfloat16))
-    return logits, new_states
+    return _quant_head(params, last), new_states
 
 
 def quant_verify_step(params, qlayers, cfg: ArchConfig, tokens, states,
@@ -411,8 +435,7 @@ def quant_verify_step(params, qlayers, cfg: ArchConfig, tokens, states,
     to 1-token greedy decode by construction.
     """
     x, _ = _quant_stack(params, qlayers, tokens, states, backend, valid_len)
-    logits = emb.logits_head(params, x.astype(jnp.bfloat16))
-    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    pred = jnp.argmax(_quant_head(params, x), axis=-1).astype(jnp.int32)
     base = valid_len - draft_len
     pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
     # draft position j matches iff the model's prediction after position
@@ -446,10 +469,10 @@ def quant_chunk_advance(params, qlayers, cfg: ArchConfig, tokens, states,
 
 def quant_prefill(params, qlayers, cfg: ArchConfig, tokens, states,
                   backend: str = "xla"):
-    """Teacher-forced integer prefill in ONE scanned pass over the prompt."""
-    logits, states = quant_forward(params, qlayers, cfg, tokens, states,
-                                   backend=backend)
-    return logits[:, -1], states
+    """Teacher-forced integer prefill in ONE scanned pass over the prompt;
+    the LM head runs only at the last position."""
+    x, states = _quant_stack(params, qlayers, tokens, states, backend)
+    return _quant_head(params, x[:, -1]), states
 
 
 def quant_decode_step(params, qlayers, cfg: ArchConfig, token, states,

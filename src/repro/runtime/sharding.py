@@ -71,23 +71,14 @@ def rules_for(profile: str) -> Dict[str, Tuple[str, ...]]:
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across JAX API generations, replication checks off.
+    """``jax.shard_map`` with the replication check (``check_vma``) off.
 
-    Newer JAX exports ``jax.shard_map`` (replication check kwarg
-    ``check_vma``); older releases only have
-    ``jax.experimental.shard_map.shard_map`` (kwarg ``check_rep``).  Every
-    shard_map body in this repo disables the check (int8-compressed psum
-    and capacity-dispatch MoE both confuse it), so one shim covers them
-    all and callers stop caring which JAX is installed.
+    Every shard_map body in this repo disables the check (int8-compressed
+    psum and capacity-dispatch MoE both confuse it), so one helper covers
+    them all.
     """
-    try:
-        from jax import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def resolve(

@@ -23,7 +23,7 @@ pytestmark = pytest.mark.fast
 @pytest.fixture(scope="module")
 def qlm():
     """Quantized smoke LSTM LM shared by every test in this module (the
-    engine/reference jit caches key on qlayers identity)."""
+    engine/reference jit caches key on the layer specs)."""
     cfg = SMOKE_CONFIGS["lstm-rnnt"]
     bundle = model_zoo.build(cfg)
     params, _ = bundle.init(jax.random.PRNGKey(0))
@@ -72,6 +72,40 @@ def test_engine_8_concurrent_streams_bitexact(qlm):
     for r in requests:
         assert results[r.rid].tokens == ref[r.rid], f"stream {r.rid} drifted"
         assert len(results[r.rid].tokens) == r.max_new_tokens
+
+
+def test_step_programs_take_weights_as_arguments(qlm):
+    """The engine's step program reads the quantized weights as jit
+    arguments, not as constants baked into the executable (which would
+    copy them into every program and pin them to one device)."""
+    params, qlayers, cfg = qlm
+    step = E._engine_step_fns(qlayers, cfg, "xla")[0]
+    weights = E.serving_weights(params, qlayers)
+    state = lstm_lm.init_quant_decode_state(qlayers, 2, per_slot_len=True)
+    compiled = step.lower(weights, jnp.zeros((2,), jnp.int32), state,
+                          jnp.ones((2,), bool)).compile()
+    qbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(weights[1]))
+    assert qbytes > 0
+    assert compiled.memory_analysis().argument_size_in_bytes >= qbytes
+    assert "lstm" not in weights[0]  # the float stack stays behind
+
+
+def test_lm_head_rows_do_not_depend_on_batch():
+    """A row's logits are the same whether the head sees it alone, in an
+    engine-sized batch, or among a prompt's positions -- at the published
+    head width, where a plain float matmul's rounding does depend on the
+    row count."""
+    rng = np.random.default_rng(0)
+    params = {"lm_head": jnp.asarray(rng.normal(size=(640, 4096)) * 0.02,
+                                     jnp.bfloat16)}
+    x = jnp.asarray(rng.normal(size=(64, 640)), jnp.float32)
+    head = jax.jit(lambda x: lstm_lm._quant_head(params, x))
+    batch = np.asarray(head(x[:8]))
+    alone = np.concatenate([np.asarray(head(x[i:i + 1])) for i in range(8)])
+    np.testing.assert_array_equal(alone, batch)
+    np.testing.assert_array_equal(np.asarray(head(x))[:8], batch)
+    np.testing.assert_array_equal(
+        np.asarray(head(x.reshape(8, 8, 640))).reshape(64, -1)[:8], batch)
 
 
 def test_admission_order_irrelevant(qlm):

@@ -49,6 +49,10 @@ inj = F.FaultInjector(kills=[dict(shard=0, at_step=5)])
 router = F.FleetRouter(params, qlayers, cfg, n_shards=2, slots_per_shard=2,
                        oversubscribe=2.0, policy="srf", injector=inj,
                        meshes=meshes)
+for sh, want in zip(router.shards, got):  # weights live on the shard's mesh
+    on = {d.id for leaf in jax.tree_util.tree_leaves(sh.engine.weights)
+          for d in leaf.devices()}
+    assert on == set(want), (on, want)
 router.warmup()
 router.submit_all(reqs)
 results, stats = router.run()

@@ -65,6 +65,28 @@ def test_hoisted_matches_stepwise_and_kernel_all_variants(variant):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("b", [1, 9])
+def test_kernel_batch_padding_keeps_rows_exact(b):
+    """The persistent kernel runs its batch padded to whole 8-row tiles;
+    B=1 (``decode_single``'s shape) and B=9 still match ``xla`` bit for
+    bit, unmasked and masked."""
+    variant = L.LSTMVariant(use_layernorm=True, use_projection=True)
+    xs_q, arrays, spec = _setup(variant, b=b)
+    h0, c0 = _state(spec, b=b)
+    valid = jnp.arange(b, dtype=jnp.int32) % (T + 1)
+    for run in (
+        lambda be: ops.quant_lstm_seq(arrays, spec, xs_q, h0, c0,
+                                      backend=be),
+        lambda be: ops.quant_lstm_seq_masked(arrays, spec, xs_q, h0, c0,
+                                             valid, backend=be),
+    ):
+        want = jax.tree_util.tree_leaves(run("xla"))
+        got = jax.tree_util.tree_leaves(run("interpret"))
+        assert [g.shape for g in got] == [w.shape for w in want]
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def _count_dot_generals(jaxpr) -> int:
     n = 0
     for eqn in jaxpr.eqns:

@@ -124,3 +124,31 @@ def test_lstm_serving_state_continuity():
     np.testing.assert_allclose(np.asarray(lp, np.float32),
                                np.asarray(ld, np.float32), rtol=2e-2,
                                atol=2e-2)
+
+
+def test_compile_cache_uses_env_dir_as_set(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, serving uses it and sets
+    nothing in code."""
+    from repro.launch import serve
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert serve.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_repo_dir(monkeypatch):
+    """Without the variable the cache goes to a fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.launch import serve
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = serve.enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

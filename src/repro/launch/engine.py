@@ -41,10 +41,22 @@ engine batch is **bitwise identical** to decoding that stream alone
 order, scheduling policy, preemption schedule, or oversubscription ratio.
 ``tests/test_engine.py`` and ``tests/test_scheduler.py`` assert this per
 stream, and the golden tests pin the absolute values.
+
+Tracing: ``run`` marks its phases with host spans on the profiler's
+timeline, which shares its clock with the device planes -- ``engine.run``
+around the call, ``engine.iteration`` around each pass of its loop, and
+inside it ``engine.schedule`` (the scheduler and each park, resume and
+admit), ``engine.feed`` (drafts, the step's input arrays and their puts
+onto the device), ``engine.dispatch`` (the jitted call, with ``program=``
+``step``, ``chunk_step``, ``chunk_advance`` or ``verify``), ``engine.sync``
+(reading the outputs back; absent on chunk advances) and ``engine.commit``
+(per-slot bookkeeping and the watchdog).  A span records only while a
+profiler session collects, and costs about a microsecond otherwise.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import math
 import time
@@ -61,6 +73,8 @@ from repro.launch.spec_decode import Drafter, NGramDrafter
 from repro.launch.state_pool import StatePool
 from repro.models import lstm_lm
 from repro.runtime.fault import StepWatchdog
+
+_span = jax.profiler.TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -930,6 +944,7 @@ class ContinuousBatchingEngine:
             preemptions=stream.preemptions,
         )
 
+    @functools.partial(jax.profiler.annotate_function, name="engine.run")
     def run(self, max_steps: Optional[int] = None, *,
             keep_live: bool = False
             ) -> Tuple[Dict[int, StreamResult], EngineStats]:
@@ -963,173 +978,195 @@ class ContinuousBatchingEngine:
         while self._queue or self._streams:
             if max_steps is not None and ran >= max_steps:
                 break
-            self._apply_schedule(time.perf_counter(), results)
-            peak_live = max(peak_live, len(self._streams))
-            if not any(rid is not None for rid in self._slot_rid):
-                # nothing runnable (all arrivals in the future): the step
-                # passes idle -- no dispatch, no active accounting (and no
-                # watchdog observation -- an idle step's wall time says
-                # nothing about device health)
+            with _span("engine.iteration"):
+                with _span("engine.schedule"):
+                    self._apply_schedule(time.perf_counter(), results)
+                peak_live = max(peak_live, len(self._streams))
+                if not any(rid is not None for rid in self._slot_rid):
+                    # nothing runnable (all arrivals in the future): the
+                    # step passes idle -- no dispatch, no active accounting
+                    # (and no watchdog observation -- an idle step's wall
+                    # time says nothing about device health)
+                    self._step += 1
+                    ran += 1
+                    continue
+                step_t0 = time.perf_counter()
+                if self._step_hook is not None:
+                    # fault-injection seam: runs INSIDE the watchdog's timed
+                    # window, so an injected sleep reads as a hung device
+                    self._step_hook(self._step)
+                with _span("engine.feed"):
+                    # speculative drafts: ask each generating stream's
+                    # drafter for up to k candidates, capped so even a
+                    # fully-accepted block lands exactly on the stream's
+                    # remaining budget (a stream one token from done never
+                    # drafts -- its drafts could never be emitted)
+                    drafts: Dict[int, List[int]] = {}
+                    if self.speculate:
+                        for i, rid in enumerate(self._slot_rid):
+                            if rid is None:
+                                continue
+                            s = self._streams[rid]
+                            if s.fed < s.request.prompt.size:
+                                continue
+                            room = s.request.max_new_tokens - len(s.generated)
+                            if room >= 2:
+                                k = min(self.speculate, room - 1)
+                                # clamp: a custom Drafter returning more
+                                # than asked must not overflow the block or
+                                # the stream budget
+                                d = list(s.drafter.draft(k))[:k]
+                                if d:
+                                    drafts[i] = d
+                    # pick this step's program: the (S, k+1) verify block
+                    # when any slot drafted; else chunked prefill when some
+                    # slot still has >= 2 prompt tokens to teacher-force;
+                    # else the one-token step -- so speculate=0 engines run
+                    # exactly the pre-speculation program sequence, and
+                    # undraftable workloads never pay the wide block
+                    slot_streams: List[Optional[_Stream]] = [
+                        self._streams[rid] if rid is not None else None
+                        for rid in self._slot_rid]
+                    chunk_pending = self.chunk > 1 and any(
+                        s is not None and s.request.prompt.size - s.fed >= 2
+                        for s in slot_streams)
+                    if drafts:
+                        # a mixed step (drafting slots + mid-prefill
+                        # co-tenants) widens to whichever program is larger:
+                        # the verify step handles arbitrary per-row
+                        # valid/draft_len, so chunked prefill must not be
+                        # capped at k+1 when chunk > k+1
+                        width = max(self.speculate + 1,
+                                    self.chunk if chunk_pending else 1)
+                    elif chunk_pending:
+                        width = self.chunk
+                    else:
+                        width = 1
+                    tokens = np.zeros((self.n_slots, width), np.int32)
+                    valid = np.zeros((self.n_slots,), np.int32)
+                    draft_len = np.zeros((self.n_slots,), np.int32)
+                    fed_before = [s.fed if s is not None else 0
+                                  for s in slot_streams]
+                    for i, s in enumerate(slot_streams):
+                        if s is None:
+                            continue
+                        rem = s.request.prompt.size - s.fed
+                        if rem >= 1:  # teacher-forced prefill: <= width
+                            n = min(width, rem)
+                            tokens[i, :n] = s.request.prompt[s.fed:s.fed + n]
+                        else:  # mid-generation: latest token (+ drafts)
+                            d = drafts.get(i, ())
+                            n = 1 + len(d)
+                            tokens[i, 0] = s.next_token()
+                            tokens[i, 1:n] = d
+                            draft_len[i] = len(d)
+                        valid[i] = n
+                    n_active = int((valid > 0).sum())
+                    active_slot_steps += n_active
+                    max_active = max(max_active, n_active)
+                    # a chunked step emits a token iff some slot consumes
+                    # its last prompt token (0 < remaining <= chunk) or is
+                    # generating (remaining == 0).  When nothing emits, the
+                    # logits would never be read: run the head-free advance
+                    # program and skip the host sync so consecutive prefill
+                    # chunks pipeline.
+                    if drafts:
+                        program = "verify"
+                    elif width == 1:
+                        program = "step"
+                    elif any(s is not None and
+                             s.request.prompt.size - s.fed <= width
+                             for s in slot_streams):
+                        program = "chunk_step"
+                    else:
+                        program = "chunk_advance"
+                    # the program's inputs, put on the device
+                    if program == "step":
+                        inputs = (self._put(jnp.asarray(tokens[:, 0])),
+                                  self._put(jnp.asarray(valid > 0)))
+                    else:
+                        inputs = (self._put(jnp.asarray(tokens)),
+                                  self._put(jnp.asarray(valid)))
+                    if program == "verify":
+                        inputs += (self._put(jnp.asarray(draft_len)),)
+                # dispatch ONE jitted program; afterwards ``consumed[i]`` is
+                # the inputs row i advanced by and ``preds[i, p]`` the greedy
+                # token following input position p (for every consumed
+                # position on verify steps; only at a row's single emitting
+                # position on the one-token / chunked paths, which emit at
+                # most one token)
+                fn = {"verify": self._verify, "step": self._step_fn,
+                      "chunk_step": self._chunk_step,
+                      "chunk_advance": self._chunk_advance}[program]
+                with _span("engine.dispatch", program=program):
+                    out = fn(self.weights, inputs[0], self._state,
+                             *inputs[1:])
+                consumed = valid
+                if program == "chunk_advance":
+                    self._state = out
+                    preds = None  # never read: no row emits this step
+                else:
+                    *out, self._state = out
+                    with _span("engine.sync"):
+                        out = [np.asarray(x) for x in out]
+                    if program == "verify":
+                        preds, consumed = out
+                        spec_steps += 1
+                    elif program == "step":
+                        preds = out[0][:, None]
+                    else:
+                        # the chunked head reads each row's LAST VALID
+                        # position, the only one the emission rule below
+                        # can select
+                        preds = np.zeros((self.n_slots, width), np.int32)
+                        for i in range(self.n_slots):
+                            if valid[i]:
+                                preds[i, valid[i] - 1] = out[0][i]
+                with _span("engine.commit"):
+                    now = time.perf_counter()
+                    for i, s in enumerate(slot_streams):
+                        if s is None:
+                            continue
+                        req = s.request
+                        n = int(consumed[i])
+                        fb = fed_before[i]
+                        # prompt tokens consumed this step (0 when
+                        # mid-generation)
+                        prompt_tokens += min(
+                            n, max(int(req.prompt.size) - fb, 0))
+                        s.fed += n
+                        if draft_len[i]:
+                            # accepted drafts = consumed inputs minus the
+                            # committed fed-back token (draft capping keeps
+                            # emissions within budget, so no accepted token
+                            # is ever discarded); the engine-wide totals are
+                            # summed from StreamResults at stats build --
+                            # every slot ends up in results
+                            s.drafted += int(draft_len[i])
+                            s.accepted_drafts += n - 1
+                            spec_slot_steps += 1
+                        for p in range(n):
+                            # consuming input position p yields a generated
+                            # token iff p is the row's last prompt token or
+                            # later
+                            if fb + p + 1 < req.prompt.size:
+                                continue
+                            s.generated.append(int(preds[i, p]))
+                            if s.drafter is not None:
+                                s.drafter.observe([s.generated[-1]])
+                            if len(s.generated) == 1:
+                                s.first_token_step = self._step
+                                s.first_token_wall = now
+                        if len(s.generated) >= req.max_new_tokens:
+                            results[req.rid] = self._result(
+                                s, self._step, now, truncated=False)
+                            generated += len(s.generated)
+                            self._slot_rid[i] = None  # evict mid-flight
+                            del self._streams[req.rid]
+                    if wd is not None:
+                        wd.observe(time.perf_counter() - step_t0)
                 self._step += 1
                 ran += 1
-                continue
-            step_t0 = time.perf_counter()
-            if self._step_hook is not None:
-                # fault-injection seam: runs INSIDE the watchdog's timed
-                # window, so an injected sleep reads as a hung device
-                self._step_hook(self._step)
-            # speculative drafts: ask each generating stream's drafter for
-            # up to k candidates, capped so even a fully-accepted block
-            # lands exactly on the stream's remaining budget (a stream one
-            # token from done never drafts -- its drafts could never be
-            # emitted)
-            drafts: Dict[int, List[int]] = {}
-            if self.speculate:
-                for i, rid in enumerate(self._slot_rid):
-                    if rid is None:
-                        continue
-                    s = self._streams[rid]
-                    if s.fed < s.request.prompt.size:
-                        continue
-                    room = s.request.max_new_tokens - len(s.generated)
-                    if room >= 2:
-                        k = min(self.speculate, room - 1)
-                        # clamp: a custom Drafter returning more than asked
-                        # must not overflow the block or the stream budget
-                        d = list(s.drafter.draft(k))[:k]
-                        if d:
-                            drafts[i] = d
-            # pick this step's program: the (S, k+1) verify block when any
-            # slot drafted; else chunked prefill when some slot still has
-            # >= 2 prompt tokens to teacher-force; else the one-token step
-            # -- so speculate=0 engines run exactly the pre-speculation
-            # program sequence, and undraftable workloads never pay the
-            # wide block
-            slot_streams: List[Optional[_Stream]] = [
-                self._streams[rid] if rid is not None else None
-                for rid in self._slot_rid]
-            chunk_pending = self.chunk > 1 and any(
-                s is not None and s.request.prompt.size - s.fed >= 2
-                for s in slot_streams)
-            if drafts:
-                # a mixed step (drafting slots + mid-prefill co-tenants)
-                # widens to whichever program is larger: the verify step
-                # handles arbitrary per-row valid/draft_len, so chunked
-                # prefill must not be capped at k+1 when chunk > k+1
-                width = max(self.speculate + 1,
-                            self.chunk if chunk_pending else 1)
-            elif chunk_pending:
-                width = self.chunk
-            else:
-                width = 1
-            tokens = np.zeros((self.n_slots, width), np.int32)
-            valid = np.zeros((self.n_slots,), np.int32)
-            draft_len = np.zeros((self.n_slots,), np.int32)
-            fed_before = [s.fed if s is not None else 0
-                          for s in slot_streams]
-            for i, s in enumerate(slot_streams):
-                if s is None:
-                    continue
-                rem = s.request.prompt.size - s.fed
-                if rem >= 1:  # teacher-forced prefill: up to `width` tokens
-                    n = min(width, rem)
-                    tokens[i, :n] = s.request.prompt[s.fed:s.fed + n]
-                else:  # mid-generation: feed back latest token (+ drafts)
-                    d = drafts.get(i, ())
-                    n = 1 + len(d)
-                    tokens[i, 0] = s.next_token()
-                    tokens[i, 1:n] = d
-                    draft_len[i] = len(d)
-                valid[i] = n
-            n_active = int((valid > 0).sum())
-            active_slot_steps += n_active
-            max_active = max(max_active, n_active)
-            # dispatch ONE jitted program; afterwards ``consumed[i]`` is the
-            # inputs row i advanced by and ``preds[i, p]`` the greedy token
-            # following input position p (for every consumed position on
-            # verify steps; only at a row's single emitting position on the
-            # one-token / chunked paths, which emit at most one token)
-            if drafts:
-                pred, accepted, self._state = self._verify(
-                    self.weights, self._put(jnp.asarray(tokens)),
-                    self._state, self._put(jnp.asarray(valid)),
-                    self._put(jnp.asarray(draft_len)))
-                preds = np.asarray(pred)
-                consumed = np.asarray(accepted)
-                spec_steps += 1
-            elif width == 1:
-                greedy, self._state = self._step_fn(
-                    self.weights, self._put(jnp.asarray(tokens[:, 0])),
-                    self._state, self._put(jnp.asarray(valid > 0)))
-                preds = np.asarray(greedy)[:, None]
-                consumed = valid
-            else:
-                # a slot emits a token this step iff it consumes its last
-                # prompt token (0 < remaining <= chunk) or is generating
-                # (remaining == 0).  When nothing emits, the logits would
-                # never be read: run the head-free advance program and skip
-                # the host sync so consecutive prefill chunks pipeline.
-                emits = any(
-                    s is not None and
-                    s.request.prompt.size - s.fed <= width
-                    for s in slot_streams)
-                consumed = valid
-                if emits:
-                    greedy, self._state = self._chunk_step(
-                        self.weights, self._put(jnp.asarray(tokens)),
-                        self._state, self._put(jnp.asarray(valid)))
-                    # the chunked head reads each row's LAST VALID position,
-                    # the only one the emission rule below can select
-                    greedy = np.asarray(greedy)
-                    preds = np.zeros((self.n_slots, width), np.int32)
-                    for i in range(self.n_slots):
-                        if valid[i]:
-                            preds[i, valid[i] - 1] = greedy[i]
-                else:
-                    preds = None  # never read: no row emits this step
-                    self._state = self._chunk_advance(
-                        self.weights, self._put(jnp.asarray(tokens)),
-                        self._state, self._put(jnp.asarray(valid)))
-            now = time.perf_counter()
-            for i, s in enumerate(slot_streams):
-                if s is None:
-                    continue
-                req = s.request
-                n = int(consumed[i])
-                fb = fed_before[i]
-                # prompt tokens consumed this step (0 when mid-generation)
-                prompt_tokens += min(n, max(int(req.prompt.size) - fb, 0))
-                s.fed += n
-                if draft_len[i]:
-                    # accepted drafts = consumed inputs minus the committed
-                    # fed-back token (draft capping keeps emissions within
-                    # budget, so no accepted token is ever discarded); the
-                    # engine-wide totals are summed from StreamResults at
-                    # stats build -- every slot ends up in results
-                    s.drafted += int(draft_len[i])
-                    s.accepted_drafts += n - 1
-                    spec_slot_steps += 1
-                for p in range(n):
-                    # consuming input position p yields a generated token
-                    # iff p is the row's last prompt token or later
-                    if fb + p + 1 < req.prompt.size:
-                        continue
-                    s.generated.append(int(preds[i, p]))
-                    if s.drafter is not None:
-                        s.drafter.observe([s.generated[-1]])
-                    if len(s.generated) == 1:
-                        s.first_token_step = self._step
-                        s.first_token_wall = now
-                if len(s.generated) >= req.max_new_tokens:
-                    results[req.rid] = self._result(
-                        s, self._step, now, truncated=False)
-                    generated += len(s.generated)
-                    self._slot_rid[i] = None  # evict mid-flight
-                    del self._streams[req.rid]
-            if wd is not None:
-                wd.observe(time.perf_counter() - step_t0)
-            self._step += 1
-            ran += 1
         # hitting max_steps leaves streams in flight: by default return
         # their partial generations (marked truncated, state discarded)
         # instead of silently dropping them -- the step that actually ran

@@ -346,6 +346,112 @@ def test_truncation_finished_step_matches_last_ran_step(qlm):
         assert res.ttft_steps == 2
 
 
+# ---------------------------------------------------------------------------
+# Host spans of ``run`` on the profiler's timeline
+# ---------------------------------------------------------------------------
+
+# a pass of each kind: step 0 idle (both requests arrive at step 1), two
+# head-free chunk advances (remaining 10 and 6 > chunk 4; 9 and 5), a chunk
+# step that emits, then one-token steps
+_SPAN_WORKLOAD = [(10, 3), (9, 2)]
+_PHASES = ("engine.schedule", "engine.feed", "engine.dispatch",
+           "engine.sync", "engine.commit")
+
+
+def _span_requests(cfg):
+    rng = np.random.default_rng(21)
+    return [E.Request(rid=i,
+                      prompt=rng.integers(0, cfg.vocab_size, size=(p,)),
+                      max_new_tokens=g, arrival=1)
+            for i, (p, g) in enumerate(_SPAN_WORKLOAD)]
+
+
+def _serve_for_spans(qlm, log_dir=None):
+    """Serve the span workload at chunk 4, under a profiler session when
+    ``log_dir`` is given; returns ``(tokens by rid, engine.* spans)``, each
+    span ``(name, start_ns, end_ns, stats)`` from the host plane."""
+    params, qlayers, cfg = qlm
+    eng = E.ContinuousBatchingEngine(params, qlayers, cfg, n_slots=3,
+                                     chunk=4)
+    eng.submit_all(_span_requests(cfg))
+    if log_dir is None:
+        results, _ = eng.run()
+        return {rid: r.tokens for rid, r in results.items()}, []
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0  # host spans only, not every Python call
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        results, _ = eng.run()
+    finally:
+        jax.profiler.stop_trace()
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    spans = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    s = int(ev.start_ns)
+                    spans.append((ev.name, s, s + int(ev.duration_ns),
+                                  {k: v for k, v in ev.stats}))
+    return ({rid: r.tokens for rid, r in results.items()},
+            sorted(spans, key=lambda x: (x[1], -x[2])))
+
+
+@pytest.fixture(scope="module")
+def profiled(qlm, tmp_path_factory):
+    return _serve_for_spans(qlm, tmp_path_factory.mktemp("profile"))
+
+
+def _inside(spans, outer):
+    return [sp for sp in spans if sp is not outer
+            and outer[1] <= sp[1] and sp[2] <= outer[2]]
+
+
+def test_run_spans_nest_phases_in_order_inside_each_iteration(profiled):
+    """One ``engine.run``; every ``engine.iteration`` lies inside it and
+    holds the phases in order: the idle pass only ``schedule``; every other
+    pass ``schedule``, ``feed``, ``dispatch``, ``sync`` (only where the
+    program emits) and ``commit``, none overlapping."""
+    _, spans = profiled
+    runs = [sp for sp in spans if sp[0] == "engine.run"]
+    assert len(runs) == 1
+    iters = [sp for sp in spans if sp[0] == "engine.iteration"]
+    assert len(iters) == 1 + 2 + 1 + 2  # idle, advances, chunk step, steps
+    assert _inside(spans, runs[0]) == [
+        sp for sp in spans if sp[0] != "engine.run"]
+    for it in iters:
+        inner = _inside(spans, it)
+        names = [sp[0] for sp in inner]
+        if it is iters[0]:
+            assert names == ["engine.schedule"]
+            continue
+        emits = inner[2][3]["program"] != "chunk_advance"
+        assert names == [n for n in _PHASES if emits or n != "engine.sync"]
+        assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+
+
+def test_dispatch_span_names_the_program(profiled):
+    _, spans = profiled
+    assert [sp[3]["program"] for sp in spans
+            if sp[0] == "engine.dispatch"] == [
+        "chunk_advance", "chunk_advance", "chunk_step", "step", "step"]
+
+
+def test_tokens_identical_with_and_without_a_profiler(qlm, profiled):
+    on, spans = profiled
+    off, none = _serve_for_spans(qlm)
+    assert spans and not none
+    assert on == off
+    assert [len(on[i]) for i in range(len(_SPAN_WORKLOAD))] == [
+        g for _, g in _SPAN_WORKLOAD]
+
+
 def test_request_and_engine_validation_raises(qlm):
     """Invariants must raise ValueError (not assert, which python -O
     strips): empty prompts, non-positive budgets, bad slot/chunk counts."""
